@@ -13,8 +13,8 @@ bit-compatible with the host loop).
 `--sharded` mode compares the "fused" backend (single device) against the
 "sharded" backend (batch axis shard_map'd over the device mesh) at equal
 batch sizes (default B in {256, 1024}) and writes BENCH_sharded_rollout.json.
-`--devices N` forces N host CPU devices by re-execing with XLA_FLAGS before
-jax initialises; results are bitwise-identical across backends, so the
+`--devices N` forces N host CPU devices through XLA_FLAGS before jax starts
+its backend; results are bitwise-identical across backends, so the
 speedup column is a free win.
 """
 from __future__ import annotations
@@ -24,17 +24,6 @@ import json
 import os
 import sys
 import time
-
-
-def _force_host_devices(n: int) -> None:
-    """Re-exec with XLA_FLAGS forcing n host devices (must happen before
-    jax backend init; safe here because main() runs before any jax call)."""
-    flag = f"--xla_force_host_platform_device_count={n}"
-    cur = os.environ.get("XLA_FLAGS", "")
-    if flag in cur:
-        return
-    os.environ["XLA_FLAGS"] = (cur + " " + flag).strip()
-    os.execv(sys.executable, [sys.executable] + sys.argv)
 
 
 def bench_host_vs_batched(args):
@@ -185,6 +174,8 @@ def bench_sharded_vs_fused(args):
 
 
 if __name__ == "__main__":
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--batch", type=int, default=32)
     ap.add_argument("--servers", type=int, default=8)
@@ -197,15 +188,16 @@ if __name__ == "__main__":
                          "of host-loop vs batched")
     ap.add_argument("--sharded-batches", default="256,1024")
     ap.add_argument("--devices", type=int, default=0,
-                    help="force N host CPU devices (re-execs with "
-                         "XLA_FLAGS before jax initialises)")
+                    help="force N host CPU devices (XLA_FLAGS, set "
+                         "before jax starts its backend)")
     ap.add_argument("--min-s", type=float, default=2.0)
     ap.add_argument("--json-out", default="",
                     help="BENCH json path ('' = repo-root default, "
                          "'none' = skip)")
     a = ap.parse_args()
     if a.devices:
-        _force_host_devices(a.devices)
+        from repro.launch.mesh import force_host_devices
+        force_host_devices(a.devices)
     sys.path.insert(0, os.path.dirname(__file__))
     if a.sharded:
         bench_sharded_vs_fused(a)

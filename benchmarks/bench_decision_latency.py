@@ -211,6 +211,8 @@ def run(verbose: bool = True, num_servers: int = 4, iters: int = 50,
 
 
 if __name__ == "__main__":
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     res, gate = run()
     payload = {"policies": res, "iters": 50, "num_servers": 4}
     if gate is not None:

@@ -34,4 +34,6 @@ def run(verbose: bool = True):
 
 
 if __name__ == "__main__":
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -74,5 +74,7 @@ def run(verbose: bool = True, mesh: str = "single") -> Optional[str]:
 
 
 if __name__ == "__main__":
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import sys
     run(mesh=sys.argv[1] if len(sys.argv) > 1 else "single")

@@ -11,9 +11,9 @@ training throughput), and the round-0 -> final episode return and
 drop-inclusive QoS-violation rate. Every window's collected replay batch is
 SHA-256 digested through the trainer's `transition_hook`; the bench asserts
 the fused and sharded digests are bitwise-identical before writing the
-record. `--devices N` forces N host CPU devices (re-exec with XLA_FLAGS
-before jax initialises) so the sharded backend runs a real multi-device
-mesh on a CPU container.
+record. `--devices N` forces N host CPU devices (XLA_FLAGS, set before jax
+starts its backend) so the sharded backend runs a multi-device mesh on a
+CPU container; on an accelerator the mesh is the chips.
 """
 from __future__ import annotations
 
@@ -22,15 +22,6 @@ import hashlib
 import os
 import sys
 import time
-
-
-def _force_host_devices(n: int) -> None:
-    flag = f"--xla_force_host_platform_device_count={n}"
-    cur = os.environ.get("XLA_FLAGS", "")
-    if flag in cur:
-        return
-    os.environ["XLA_FLAGS"] = (cur + " " + flag).strip()
-    os.execv(sys.executable, [sys.executable] + sys.argv)
 
 
 def eval_policy_stream(ecfg, acfg, actor_params, backend, args,
@@ -169,8 +160,8 @@ def main():
     ap.add_argument("--json-out", default=None)
     args = ap.parse_args()
     if args.devices > 1:
-        _force_host_devices(args.devices)
-
+        from repro.launch.mesh import force_host_devices
+        force_host_devices(args.devices)
     sys.path.insert(0, os.path.dirname(__file__))
     from common import write_bench_json
 
@@ -195,4 +186,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

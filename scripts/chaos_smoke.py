@@ -114,4 +114,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

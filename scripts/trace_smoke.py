@@ -104,4 +104,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -47,8 +47,15 @@ class ActorProgram:
         self._vmapped = None
 
     def _split_act(self, trace, state, obs, key, params):
+        # the actor runs as the batch-axis view on a batch of one, so this
+        # seam and the fused scan at B=1 are one program: compiled
+        # unbatched, XLA:CPU contracts the reverse chain's multiply-adds
+        # differently and the actions drift by a few ulps
         key, k_act = jax.random.split(key)
-        action, extras = self.policy(params, k_act, trace, state, obs)
+        batch = jax.tree_util.tree_map(lambda x: x[None],
+                                       (k_act, trace, state, obs))
+        out = self.vmapped(params, *batch)
+        action, extras = jax.tree_util.tree_map(lambda x: x[0], out)
         return key, action, extras
 
     def act(self, trace, state, obs, key, params):

@@ -40,7 +40,6 @@ import warnings
 from typing import Optional
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.api.specs import BACKENDS, ExecSpec
@@ -86,8 +85,8 @@ def _sharded_program(ecfg: EV.EnvConfig, policy, num_steps: Optional[int],
                                 fused=True, fused_impl=fused_impl)
 
     in_specs = (P(axis), P(), P(axis)) + ((P(axis),) if has_init else ())
-    f = shard_map(run, mesh=mesh, in_specs=in_specs,
-                  out_specs=P(axis), check_rep=False)
+    f = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                      out_specs=P(axis), check_vma=False)
     return jax.jit(f)
 
 

@@ -14,6 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _mish(x):
@@ -28,21 +29,20 @@ def _pin(x):
     return jnp.minimum(x, 1e30)
 
 
+def _dot(a, b):
+    """a @ b, float32 out, at the ambient matmul precision: the actor's
+    precision is the caller's (`jax.default_matmul_precision`), the same
+    for these kernels as for the XLA paths around them."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _denoiser_kernel(inp_ref, w1_ref, b1_ref, w2_ref, b2_ref, w3_ref, b3_ref,
                      out_ref):
     x = inp_ref[...].astype(jnp.float32)
-    h = _mish(jax.lax.dot_general(x, w1_ref[...].astype(jnp.float32),
-                                  (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-              + b1_ref[...])
-    h = _mish(jax.lax.dot_general(h, w2_ref[...].astype(jnp.float32),
-                                  (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-              + b2_ref[...])
-    o = jnp.tanh(jax.lax.dot_general(h, w3_ref[...].astype(jnp.float32),
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-                 + b3_ref[...])
+    h = _mish(_dot(x, w1_ref[...].astype(jnp.float32)) + b1_ref[...])
+    h = _mish(_dot(h, w2_ref[...].astype(jnp.float32)) + b2_ref[...])
+    o = jnp.tanh(_dot(h, w3_ref[...].astype(jnp.float32)) + b3_ref[...])
     out_ref[...] = o.astype(out_ref.dtype)
 
 
@@ -77,11 +77,12 @@ def denoiser_step(inp, w1, b1, w2, b2, w3, b3, *, block_b: int = 128,
     return out[:B]
 
 
-def _chain_kernel(x_ref, noises_ref, f_ref, temb_ref, cx_ref, ce_ref,
-                  cn_ref, w1_ref, b1_ref, w2_ref, b2_ref, w3_ref, b3_ref,
-                  out_ref):
+def _chain_kernel(x_ref, noises_ref, f_ref, temb_ref, coef_ref, w1_ref,
+                  b1_ref, w2_ref, b2_ref, w3_ref, b3_ref, out_ref):
     """All K reverse steps for one batch block, weights resident across the
-    whole chain — one kernel launch per decision instead of K."""
+    whole chain — one kernel launch per decision instead of K. The (3, K)
+    chain coefficients sit in SMEM: step j reads three scalars at a loop
+    index, which a vector load from VMEM cannot do."""
     w1 = w1_ref[...].astype(jnp.float32)
     b1 = b1_ref[...]
     w2 = w2_ref[...].astype(jnp.float32)
@@ -95,17 +96,11 @@ def _chain_kernel(x_ref, noises_ref, f_ref, temb_ref, cx_ref, ce_ref,
     def step(j, x):
         t_b = jnp.broadcast_to(temb_ref[j], (block_b, t_dim))
         inp = jnp.concatenate([x, t_b, f], axis=-1)
-        h = _mish(jax.lax.dot_general(inp, w1, (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-                  + b1)
-        h = _mish(jax.lax.dot_general(h, w2, (((1,), (0,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-                  + b2)
-        eps = jnp.tanh(jax.lax.dot_general(h, w3, (((1,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
-                       + b3)
-        return (_pin(cx_ref[j] * x) + _pin(ce_ref[j] * eps)
-                + _pin(cn_ref[j] * noises_ref[j]))
+        h = _mish(_dot(inp, w1) + b1)
+        h = _mish(_dot(h, w2) + b2)
+        eps = jnp.tanh(_dot(h, w3) + b3)
+        return (_pin(coef_ref[0, j] * x) + _pin(coef_ref[1, j] * eps)
+                + _pin(coef_ref[2, j] * noises_ref[j]))
 
     x0 = jax.lax.fori_loop(0, K, step, x_ref[...].astype(jnp.float32))
     out_ref[...] = jnp.tanh(x0).astype(out_ref.dtype)
@@ -142,9 +137,7 @@ def denoiser_chain(x, noises, f_s, tembs, coef_x, coef_e, coef_n,
             pl.BlockSpec((K, block_b, a), lambda i: (0, i, 0)),
             pl.BlockSpec((block_b, fdim), lambda i: (i, 0)),
             pl.BlockSpec((K, t_dim), lambda i: (0, 0)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((K,), lambda i: (0,)),
-            pl.BlockSpec((K,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((a + t_dim + fdim, h1), lambda i: (0, 0)),
             pl.BlockSpec((h1,), lambda i: (0,)),
             pl.BlockSpec((h1, h2), lambda i: (0, 0)),
@@ -155,5 +148,6 @@ def denoiser_chain(x, noises, f_s, tembs, coef_x, coef_e, coef_n,
         out_specs=pl.BlockSpec((block_b, a), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B + bp, a), x.dtype),
         interpret=interpret,
-    )(x_p, n_p, f_p, tembs, coef_x, coef_e, coef_n, w1, b1, w2, b2, w3, b3)
+    )(x_p, n_p, f_p, tembs, jnp.stack([coef_x, coef_e, coef_n]),
+      w1, b1, w2, b2, w3, b3)
     return out[:B]

@@ -53,8 +53,15 @@ def resolve_impl(impl: str = "auto") -> str:
     return impl
 
 
+def _interpret(interpret) -> bool:
+    """Compiled kernels on gpu/tpu, the Pallas interpreter elsewhere."""
+    if interpret is None:
+        return jax.default_backend() not in ("gpu", "tpu")
+    return bool(interpret)
+
+
 def denoise_eps_fused(denoiser_params, x, i, f_s, t_dim: int = 16,
-                      interpret: bool = True):
+                      interpret=None):
     """Drop-in for repro.core.diffusion.denoise_eps (batched inputs)."""
     w1, b1, w2, b2, w3, b3 = _flat_weights(denoiser_params)
     temb = timestep_embedding(i, t_dim)
@@ -62,7 +69,8 @@ def denoise_eps_fused(denoiser_params, x, i, f_s, t_dim: int = 16,
     squeeze = inp.ndim == 1
     if squeeze:
         inp = inp[None]
-    out = denoiser_step(inp, w1, b1, w2, b2, w3, b3, interpret=interpret)
+    out = denoiser_step(inp, w1, b1, w2, b2, w3, b3,
+                        interpret=_interpret(interpret))
     return out[0] if squeeze else out
 
 
@@ -80,11 +88,9 @@ def denoise_chain(denoiser_params, x, noises, f_s, tembs, coef_x, coef_e,
     if impl == "ref":
         return KREF.denoiser_chain_ref(x, noises, f_s, tembs,
                                        coef_x, coef_e, coef_n, *w)
-    if interpret is None:
-        interpret = jax.default_backend() not in ("gpu", "tpu")
     squeeze = x.ndim == 1
     if squeeze:
         x, noises, f_s = x[None], noises[:, None], f_s[None]
     out = denoiser_chain(x, noises, f_s, tembs, coef_x, coef_e, coef_n,
-                         *w, block_b=block_b, interpret=bool(interpret))
+                         *w, block_b=block_b, interpret=_interpret(interpret))
     return out[0] if squeeze else out

@@ -149,7 +149,9 @@ def _env_step_kernel(cfg: EV.EnvConfig, faults: bool, *refs):
     rank = jnp.sum((score[:, None, :] < score[:, :, None]).astype(_I32),
                    axis=2)
     fresh_sel = idle & (rank < c_k)
-    sel = jnp.where(reuse, reuse_sel, fresh_sel)
+    # a select between two bool vectors lowers to an i8->i1 truncation that
+    # Mosaic rejects; the and/or form is the same mask
+    sel = (reuse & reuse_sel) | (~reuse & fresh_sel)
 
     # --- timing / quality of the candidate decision -----------------------
     # env._pin blocks FMA contraction of product-then-add chains: the
@@ -173,10 +175,13 @@ def _env_step_kernel(cfg: EV.EnvConfig, faults: bool, *refs):
     if faults:
         # in-flight failure: a selected server crashes before the gang
         # finishes (status 3, servers freed at the crash, no reward)
+        # (the gang mask is applied after the F-reduction: Mosaic cannot
+        # broadcast a bool vector to rank 3, and min is exact either way)
         fin3 = finish[:, :, None]       # (bb, 1, 1)
-        crash_cand = sel[:, :, None] & (ds > t3) & (ds < fin3)    # (bb, E, F)
-        crash_t = jnp.min(jnp.min(jnp.where(crash_cand, ds, 1e30), axis=2),
-                          axis=1, keepdims=True)
+        crash_e = jnp.min(jnp.where((ds > t3) & (ds < fin3), ds, 1e30),
+                          axis=2)                                 # (bb, E)
+        crash_t = jnp.min(jnp.where(sel, crash_e, 1e30), axis=1,
+                          keepdims=True)
         will_fail = crash_t < 1e30
         sched_status = jnp.where(will_fail, 3, 1)
         rec_finish = jnp.where(will_fail, crash_t, finish)
@@ -292,7 +297,7 @@ def env_step_pallas(cfg: EV.EnvConfig, time, free, smodel, sgang, sgsize,
                     arr, c, model, noise, step_base, init_base, scale,
                     action, qidx, qvalid, qqueued, *,
                     fds=None, fde=None, fslow=None, fcold=None,
-                    block_b: int = 256, interpret: bool = True):
+                    block_b: int = 32, interpret: bool = True):
     """Raw batched kernel entry: (B, ...) arrays in, tuple of 18 arrays out.
 
     Per-env scalars are (B, 1); boolean masks are int32 0/1 on both sides.
@@ -301,6 +306,12 @@ def env_step_pallas(cfg: EV.EnvConfig, time, free, smodel, sgang, sgsize,
     cold-restart flag — see `repro.faults.schedule`) switches the kernel
     into fault mode; leaving them None traces the exact fault-free program.
     Use ``ops.env_step_fused`` for the EnvState/QueueView-level wrapper.
+
+    `block_b` rows go to one grid step. Every (bb, E|K) block pads its
+    lane axis to 128 and the pairwise ranks build (bb, E, E) and
+    (bb, K, K) intermediates, so on a TPU a block of 128 rows with the
+    fault columns already overruns scoped VMEM; 32 leaves room for
+    E = K = 32.
     """
     faults = fds is not None
     B = time.shape[0]

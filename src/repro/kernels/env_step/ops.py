@@ -34,7 +34,7 @@ def resolve_impl(impl: str = "auto") -> str:
 
 def env_step_fused(ecfg: EV.EnvConfig, statics, state: EV.EnvState,
                    action, queue: EV.QueueView, *, impl: str = "auto",
-                   block_b: int = 256, interpret=None):
+                   block_b: int = 32, interpret=None):
     """One fused decision for B envs.
 
     All of `statics` (per-task constants from ``env.decision_statics``),

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes and record memory/cost/collective analysis.
 
@@ -10,11 +7,12 @@ Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both \
         --out artifacts/dryrun
 
-The XLA_FLAGS line above MUST run before any jax import: jax locks the
-device count at first initialisation.
+`main` forces 512 host devices (`mesh.force_host_devices`) before anything
+starts a jax backend, which locks the device count.
 """
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -22,7 +20,7 @@ import jax
 
 from repro.common.config import ASSIGNED_ARCHS, get_config
 from repro.launch import hlo_analysis as HA
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import force_host_devices, make_production_mesh
 from repro.launch.shapes import SHAPES, adapt_config
 from repro.launch.steps import build_case, lower_case
 
@@ -65,7 +63,7 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, **case_kw):
         rec["useful_flop_ratio"] = (mf / n_dev) / per_dev if per_dev else 0.0
         rec["status"] = "ok"
         print(compiled.memory_analysis())
-        ca = HA.cost_dict(compiled)
+        ca = compiled.cost_analysis()
         print({k: ca[k] for k in ("flops", "bytes accessed") if k in ca})
     except Exception as e:  # noqa: BLE001 — record and continue the sweep
         rec["status"] = "error"
@@ -92,6 +90,7 @@ def main():
     ap.add_argument("--resume", action="store_true",
                     help="skip pairs whose artifact JSON already has status ok/skipped")
     args = ap.parse_args()
+    force_host_devices(512)
 
     archs = list(ASSIGNED_ARCHS) if args.all or args.arch is None else [args.arch]
     shapes = list(SHAPES) if args.all or args.shape is None else [args.shape]

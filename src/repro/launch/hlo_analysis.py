@@ -73,17 +73,8 @@ def roofline_terms(cost: Dict, coll: Dict, *, num_links: int = 4) -> Dict:
     return terms
 
 
-def cost_dict(compiled) -> Dict:
-    """compiled.cost_analysis() version shim: jax <= 0.4.x returns a list of
-    per-program dicts, newer jax returns the dict directly."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def analyze_compiled(compiled) -> Dict:
-    cost = cost_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     mem = compiled.memory_analysis()
     out = roofline_terms(cost, coll)

@@ -7,12 +7,10 @@ XLA_FLAGS=--xla_force_host_platform_device_count=512 before first jax init.
 """
 from __future__ import annotations
 
-import jax
+import os
 
-try:  # jax >= 0.5: explicit axis types on the mesh
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+import jax
+from jax.sharding import AxisType
 
 # TPU v5e hardware constants (per chip) for the roofline model
 PEAK_FLOPS_BF16 = 197e12        # FLOP/s
@@ -20,21 +18,20 @@ HBM_BW = 819e9                  # bytes/s
 ICI_BW = 50e9                   # bytes/s per link
 
 
+def force_host_devices(n: int) -> None:
+    """Give the CPU backend `n` devices, in this process. XLA reads the
+    flag when a backend starts, and importing `repro.core` starts one, so
+    an entry point calls this before it imports anything else of the
+    repo. It adds no device on an accelerator, where the mesh is the
+    chips."""
+    flag = f"--xla_force_host_platform_device_count={n}"
+    cur = os.environ.get("XLA_FLAGS", "")
+    if flag not in cur:
+        os.environ["XLA_FLAGS"] = (cur + " " + flag).strip()
+
+
 def _make_mesh(shape, axes):
-    """Version-compat mesh constructor (jax 0.4.x .. current)."""
-    if AxisType is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(AxisType.Auto,) * len(axes))
-        except TypeError:  # make_mesh exists but predates axis_types
-            pass
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    import numpy as np
-    from jax.sharding import Mesh
-    n = int(np.prod(shape))
-    devices = np.asarray(jax.devices()[:n]).reshape(shape)
-    return Mesh(devices, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

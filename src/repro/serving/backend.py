@@ -237,6 +237,8 @@ class ServingRollout:
             for s in gang:
                 s.params, s.model_name = leader.params, leader.model_name
         else:
+            for s in gang:      # a cold gang drops what its members held
+                s.params, s.model_name = None, None   # before it loads
             self._load(gang[0], arch)
             for s in gang[1:]:
                 # each member materialises the weights in the real system;
@@ -331,6 +333,7 @@ class ServingRollout:
                                       server=int(i), arch=arch):
                     self._prefetch_key, k = jax.random.split(
                         self._prefetch_key)
+                    s.params = None     # release the old copy first
                     s.params = self.executor.init_params(arch, k)
                     s.model_name = arch
                 self.placement_prefetches += 1

@@ -1,8 +1,11 @@
 """Real prefill/decode execution for the serving layer.
 
 `ModelExecutor` owns the model zoo instances (reduced or full configs), the
-per-arch jitted prefill/decode programs, and the generation loop the engine
-and the serving backend both call. Two correctness properties live here:
+per-arch jitted init/prefill/decode programs, and the generation loop the
+engine and the serving backend both call. Reduced configs are the CPU test
+models and run in float32, which their bitwise pins need; published widths
+serve in bfloat16, the zoo's own compute dtype (one qwen2-1.5b copy is
+3.1 GB there, 6.2 GB in float32). Two correctness properties live here:
 
 KV-cache sizing. The scheduler picks the inference-step count (up to
 ``s_max``) independently of the request's ``max_new_tokens``; the decode
@@ -27,7 +30,7 @@ accounts the parallel speedup either way.
 from __future__ import annotations
 
 import time
-from typing import Callable, Dict, Optional
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -61,13 +64,13 @@ def chunkable(cfg) -> bool:
 
 
 def _merge_chunk_cache(model: Model, ccache: Dict, S_pad: int,
-                       capacity: int) -> Dict:
+                       capacity: int, dtype) -> Dict:
     """(c, chunk)-batched prefill caches -> one (1, capacity) decode cache.
 
     Chunks are consecutive prompt slices, so concatenating their KV along
     the sequence axis — a reshape of (periods, c, chunk, kv, hd) — restores
     prompt order exactly; `pos = S_pad` points decode past the merged KV."""
-    big = model.make_cache(1, capacity, dtype=jnp.float32)
+    big = model.make_cache(1, capacity, dtype=dtype)
 
     def merge(dst, src):
         npd, c, chunk, nk, hd = src.shape
@@ -80,6 +83,48 @@ def _merge_chunk_cache(model: Model, ccache: Dict, S_pad: int,
     return {"periods": periods, "pos": jnp.asarray(S_pad, jnp.int32)}
 
 
+class _Programs(NamedTuple):
+    """One arch's jitted programs. Each returns the next greedy token with
+    the cache, so a generate step dispatches one program and nothing else."""
+    init: object
+    prefill: object           # (params, batch, capacity=) -> (tok, cache)
+    prefill_chunked: object   # (params, chunks, capacity=) -> (tok, cache)
+    decode: object            # (params, cache, tok) -> (tok, cache)
+
+
+def _programs(model: Model, dtype) -> _Programs:
+    vocab = model.cfg.vocab_size
+
+    def next_tok(logits):
+        return jnp.argmax(logits[:, -1:, :vocab], axis=-1).astype(jnp.int32)
+
+    def prefill(params, batch, capacity):
+        cache = model.make_cache(1, capacity, dtype=dtype)
+        logits, cache = model.prefill(params, batch, cache,
+                                      compute_dtype=dtype)
+        return next_tok(logits), cache
+
+    def prefill_chunked(params, chunks, capacity):
+        c, chunk = chunks.shape
+        ccache = model.make_cache(c, chunk, dtype=dtype)
+        logits, ccache = model.prefill(params, {"tokens": chunks}, ccache,
+                                       compute_dtype=dtype)
+        cache = _merge_chunk_cache(model, ccache, c * chunk, capacity, dtype)
+        # the prompt's last token ends chunk c-1
+        return next_tok(logits[-1:]), cache
+
+    def decode(params, cache, tok):
+        logits, cache = model.decode(params, cache, tok, compute_dtype=dtype)
+        return next_tok(logits), cache
+
+    return _Programs(
+        init=jax.jit(lambda key: model.init(key, dtype)),
+        prefill=jax.jit(prefill, static_argnames=("capacity",)),
+        prefill_chunked=jax.jit(prefill_chunked,
+                                static_argnames=("capacity",)),
+        decode=jax.jit(decode))
+
+
 class ModelExecutor:
     """Cached models + jitted inference programs, shared by every server.
 
@@ -89,11 +134,10 @@ class ModelExecutor:
 
     def __init__(self, reduced: bool = True, tracer=None):
         self.reduced = reduced
+        self.dtype = jnp.float32 if reduced else jnp.bfloat16
         self.tracer = NULL_TRACER if tracer is None else tracer
         self._models: Dict[str, Model] = {}
-        self._prefill: Dict[str, Callable] = {}
-        self._decode: Dict[str, Callable] = {}
-        self._warm_params: Dict[str, object] = {}   # throwaway compile params
+        self._progs: Dict[str, _Programs] = {}
         self._warmed: set = set()                   # shape buckets compiled
 
     def model(self, arch: str) -> Model:
@@ -101,18 +145,14 @@ class ModelExecutor:
             cfg = get_config(arch)
             model = build_model(cfg.reduced() if self.reduced else cfg)
             self._models[arch] = model
-            self._prefill[arch] = jax.jit(
-                lambda p, b, c, m=model: m.prefill(
-                    p, b, c, compute_dtype=jnp.float32))
-            self._decode[arch] = jax.jit(
-                lambda p, c, t, m=model: m.decode(
-                    p, c, t, compute_dtype=jnp.float32))
+            self._progs[arch] = _programs(model, self.dtype)
         return self._models[arch]
 
     def init_params(self, arch: str, key):
         """Real weight materialisation — the cold-start cost being scheduled
         around (the Table-VI init_time stands in for its wall-clock)."""
-        return self.model(arch).init(key)
+        self.model(arch)
+        return self._progs[arch].init(key)
 
     # ------------------------------------------------------------------
     def shape_key(self, arch: str, prompt_len: int, c: int, steps: int,
@@ -129,26 +169,34 @@ class ModelExecutor:
 
     def warm(self, arch: str, prompt_len: int, c: int, steps: int,
              max_new_tokens: int) -> bool:
-        """Pre-compile the prefill/decode programs a `generate` with these
-        arguments would hit; returns True when compilation actually ran.
+        """Pre-compile the init/prefill/decode programs a `generate` with
+        these arguments would hit; returns True when compilation ran.
 
-        Runs one throwaway single-step generate with per-arch cached dummy
-        params (identical shapes, so the jit cache hits) against the SAME
-        chunk shape and cache capacity: `max_new_tokens` is inflated to
-        keep the capacity bucket fixed while `steps=1` bounds the warm
-        decode work. Uses `jax.random.PRNGKey(0)` directly — the serving
-        backend's `_load_key` stream is untouched, so warmed and unwarmed
-        runs schedule identically."""
+        Compiles from shapes alone (`jax.eval_shape` parameters and cache),
+        so warming holds no weights on the device; jit's cache then serves
+        the later calls with real arrays. Draws no key from the serving
+        backend's streams, so warmed and unwarmed runs schedule
+        identically."""
         k = self.shape_key(arch, prompt_len, c, steps, max_new_tokens)
         if k in self._warmed:
             return False
-        _arch, _c, S_pad, capacity = k
-        if arch not in self._warm_params:
-            self._warm_params[arch] = self.init_params(
-                arch, jax.random.PRNGKey(0))
-        prompt = np.zeros(int(prompt_len), np.int32)
-        self.generate(arch, self._warm_params[arch], prompt, c, 1,
-                      capacity - S_pad)
+        _arch, cc, S_pad, capacity = k
+        progs = self._progs[arch]
+        key = jax.random.PRNGKey(0)
+        if not any(w[0] == arch for w in self._warmed):
+            progs.init.lower(key).compile()
+        params = jax.eval_shape(progs.init, key)
+        cfg = self.model(arch).cfg
+        if chunkable(cfg):
+            prefill = progs.prefill_chunked
+            batch = jnp.zeros((cc, S_pad // cc), jnp.int32)
+        else:       # the unchunked path prefills the unpadded prompt
+            prefill = progs.prefill
+            batch = self._full_batch(cfg, np.zeros(int(prompt_len), np.int32))
+        prefill.lower(params, batch, capacity=capacity).compile()
+        tok, cache = jax.eval_shape(
+            lambda p, b: prefill(p, b, capacity=capacity), params, batch)
+        progs.decode.lower(params, cache, tok).compile()
         self._warmed.add(k)
         return True
 
@@ -176,6 +224,7 @@ class ModelExecutor:
         in `serving.backend` catches it); 0 disables the check."""
         t_start = time.perf_counter()
         model = self.model(arch)
+        progs = self._progs[arch]
         cfg = model.cfg
         prompt = np.asarray(prompt, np.int32)
         c = max(int(c), 1)
@@ -194,21 +243,14 @@ class ModelExecutor:
                 # chunk — its last-position logits are the next-token
                 # distribution
                 chunks = jnp.asarray(np.pad(prompt, (pad, 0)).reshape(c, -1))
-                ccache = model.make_cache(c, chunks.shape[1],
-                                          dtype=jnp.float32)
-                logits, ccache = self._prefill[arch](
-                    params, {"tokens": chunks}, ccache)
-                cache = _merge_chunk_cache(model, ccache, S_pad, capacity)
-                logits = logits[-1:]  # prompt's last token ends chunk c-1
+                tok, cache = progs.prefill_chunked(params, chunks,
+                                                   capacity=capacity)
             else:
-                cache = model.make_cache(1, capacity, dtype=jnp.float32)
-                logits, cache = self._prefill[arch](
-                    params, self._full_batch(cfg, prompt), cache)
+                tok, cache = progs.prefill(
+                    params, self._full_batch(cfg, prompt), capacity=capacity)
             if tr.enabled:   # wall attribution only: sync inside the span
-                jax.block_until_ready(logits)
+                jax.block_until_ready(tok)
         out = []
-        tok = jnp.argmax(logits[:, -1:, : cfg.vocab_size],
-                         axis=-1).astype(jnp.int32)
         with tr.span("decode", cat="serving", arch=arch, steps=steps,
                      capacity=capacity):
             for i in range(steps):
@@ -217,8 +259,6 @@ class ModelExecutor:
                     raise ExecutorTimeout(
                         f"{arch} generate exceeded {deadline_s:.1f}s "
                         f"budget at decode step {i}/{steps}")
-                out.append(int(tok[0, 0]))
-                logits, cache = self._decode[arch](params, cache, tok)
-                tok = jnp.argmax(logits[:, -1:, : cfg.vocab_size],
-                                 axis=-1).astype(jnp.int32)
+                out.append(int(np.asarray(tok)[0, 0]))
+                tok, cache = progs.decode(params, cache, tok)
         return np.asarray(out, np.int32)

@@ -106,3 +106,36 @@ def test_batch_spec_degrades():
     assert batch_spec(mesh, 16) == ("data",)
     # batch=1 divides a 1-wide axis, so it stays
     assert batch_spec(mesh, 1) == ("data",)
+
+
+# ---------------------------------------------------------- compile cache
+@pytest.fixture
+def cache_dir_config():
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_honours_the_environment(monkeypatch,
+                                               cache_dir_config, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX's own reading of it stands:
+    the helper reports that directory and sets no other."""
+    from repro.common import compile_cache as CC
+    monkeypatch.setenv(CC.ENV_VAR, str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert CC.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_falls_back_to_the_checkout(monkeypatch,
+                                                  cache_dir_config):
+    """Unset, the cache goes to one fixed, gitignored directory at the root
+    of the checkout."""
+    from repro.common import compile_cache as CC
+    monkeypatch.delenv(CC.ENV_VAR, raising=False)
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    want = os.path.join(root, ".jax_cache")
+    assert CC.enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

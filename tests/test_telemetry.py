@@ -256,6 +256,36 @@ def test_executor_warmup_memoizes_shape_buckets():
     assert ex.warm("tinyllama-1.1b", 8, 1, 6, 8) is False
 
 
+@pytest.mark.parametrize("arch,c", [("tinyllama-1.1b", 1),
+                                    ("tinyllama-1.1b", 3),
+                                    ("xlstm-125m", 2)])
+def test_executor_warmup_compiles_from_shapes(arch, c):
+    """`warm` compiles every program the matching `generate` runs (chunked
+    and unchunked prefill, decode, weight init) from shapes alone: it
+    leaves no array on the device, and the first generate compiles
+    nothing."""
+    from repro.serving.executor import ModelExecutor
+    ex = ModelExecutor(reduced=True)
+    ex.model(arch)
+    live = {id(a) for a in jax.live_arrays()}
+    assert ex.warm(arch, 8, c, 6, 8) is True
+    assert not [a for a in jax.live_arrays() if id(a) not in live]
+    compiles = []
+
+    def listen(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        params = ex.init_params(arch, jax.random.PRNGKey(0))
+        toks = ex.generate(arch, params, np.arange(8, dtype=np.int32), c, 6,
+                           8)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert len(toks) == 6
+    assert compiles == []
+
+
 def test_executor_warmup_removes_first_task_compile_cost():
     """After `warm`, the first timed generate is steady-state work, not an
     XLA compile: it must be far cheaper than a cold executor's first call
