@@ -66,10 +66,17 @@ class ActorProgram:
     @property
     def vmapped(self):
         """The fused-scan view: `vmap(policy, (None, 0, 0, 0, 0))` (shared
-        params, batched key/trace/state/obs)."""
+        params, batched key/trace/state/obs). Its ops carry the named
+        scope `policy` in a profile."""
         if self._vmapped is None:
-            self._vmapped = jax.vmap(self.policy,
-                                     in_axes=(None, 0, 0, 0, 0))
+            policy = self.policy
+
+            @functools.wraps(policy)
+            def scoped(*args):
+                with jax.named_scope("policy"):
+                    return policy(*args)
+
+            self._vmapped = jax.vmap(scoped, in_axes=(None, 0, 0, 0, 0))
         return self._vmapped
 
     def __repr__(self):

@@ -146,8 +146,9 @@ def _batch_rollout_fused(ecfg: EV.EnvConfig, traces: Dict, policy: Policy,
         splits = jax.vmap(jax.random.split)(ks)          # (B, 2, 2)
         ks_next, k_act = splits[:, 0], splits[:, 1]
         action, extras = vpolicy(params, k_act, traces, state, obs)
-        nstate, nq, nobs, r, d = EK.env_step_fused(
-            ecfg, statics, state, action, q, impl=impl)
+        with jax.named_scope("env_step"):
+            nstate, nq, nobs, r, d = EK.env_step_fused(
+                ecfg, statics, state, action, q, impl=impl)
         nstate = jax.tree_util.tree_map(
             lambda n, o: jnp.where(_bcast(done, n), o, n), nstate, state)
         nq = jax.tree_util.tree_map(

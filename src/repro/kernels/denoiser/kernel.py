@@ -73,6 +73,7 @@ def denoiser_step(inp, w1, b1, w2, b2, w3, b3, *, block_b: int = 128,
         out_specs=pl.BlockSpec((block_b, a), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B + bp, a), inp.dtype),
         interpret=interpret,
+        name="denoiser_step",
     )(inp_p, w1, b1, w2, b2, w3, b3)
     return out[:B]
 
@@ -148,6 +149,7 @@ def denoiser_chain(x, noises, f_s, tembs, coef_x, coef_e, coef_n,
         out_specs=pl.BlockSpec((block_b, a), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B + bp, a), x.dtype),
         interpret=interpret,
+        name="denoiser_chain",
     )(x_p, n_p, f_p, tembs, jnp.stack([coef_x, coef_e, coef_n]),
       w1, b1, w2, b2, w3, b3)
     return out[:B]

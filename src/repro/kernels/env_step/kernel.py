@@ -363,6 +363,7 @@ def env_step_pallas(cfg: EV.EnvConfig, time, free, smodel, sgang, sgsize,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="env_step_pallas",
     )(*ins)
     if pad:
         outs = [o[:B] for o in outs]

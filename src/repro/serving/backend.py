@@ -290,21 +290,19 @@ class ServingRollout:
                         arch, params, prompt, c_k, run_steps,
                         self.max_new_tokens, deadline_s=spec.exec_timeout_s)
                 return
-            except ExecutorFault as err:
+            except ExecutorFault:
                 self.pool.exec_failures += 1
                 if attempt == attempts:
                     self.pool.exec_gave_up += 1
                     return          # every attempt failed: serve nothing
                 self.pool.exec_retries += 1
-                with self.tracer.span("executor_retry", cat="serving",
-                                      arch=arch, attempt=attempt,
-                                      error=type(err).__name__):
-                    pass
 
     def _load(self, server, arch: str) -> None:
         with self.tracer.span("model_load", cat="serving", arch=arch):
             self._load_key, k = jax.random.split(self._load_key)
             server.params = self.executor.init_params(arch, k)
+            if self.tracer.enabled:  # wall attribution only: sync inside
+                jax.block_until_ready(server.params)
         server.model_name = arch
         self.pool.load_count += 1
 
@@ -320,9 +318,7 @@ class ServingRollout:
         sp = decision.streams[0]            # serving is one physical cluster
         for i in np.flatnonzero(sp.evict):
             s = self.pool.servers[i]
-            with self.tracer.span("evict", cat="placement", server=int(i),
-                                  arch=s.model_name or ""):
-                s.params, s.model_name = None, None
+            s.params, s.model_name = None, None
             self.placement_evictions += 1
         warmed = set()
         for i in np.flatnonzero(sp.prefetch):

@@ -24,6 +24,7 @@ KNOWN_SPANS: Dict[str, Tuple[str, str]] = {
     "build_window":     ("stream",   "traffic.StreamRunner"),
     "window_rollout":   ("rollout",  "traffic.StreamRunner"),
     "window_seam":      ("stream",   "traffic.StreamRunner"),
+    "window_record":    ("stream",   "traffic.StreamRunner"),
     "fault_requeue":    ("stream",   "traffic.StreamRunner"),
     # streaming trainers (repro.training.stream_train)
     "train_round":      ("train",    "training.stream_train"),
@@ -40,12 +41,10 @@ KNOWN_SPANS: Dict[str, Tuple[str, str]] = {
     "prefill":          ("serving",  "serving.ModelExecutor"),
     "decode":           ("serving",  "serving.ModelExecutor"),
     # serving fault tolerance (repro.serving.backend)
-    "executor_retry":   ("serving",  "serving.ServingRollout"),
     "executor_degrade": ("serving",  "serving.ServingRollout"),
     # slow-timescale placement (repro.placement / serving.backend)
     "placement_decide": ("placement", "placement.PlacementManager"),
     "prefetch":         ("placement", "serving.ServingRollout"),
-    "evict":            ("placement", "serving.ServingRollout"),
 }
 
 _EVENT_SCHEMA = {
@@ -161,25 +160,35 @@ def assert_valid_trace(path: str, *, strict_names: bool = False) -> None:
 
 def span_durations(events: Iterable[dict]) -> Dict[str, Dict[str, float]]:
     """Aggregate complete-span events -> {name: {count, total_s, mean_s,
-    self_total_s}}. `self_total_s` subtracts the time spent in directly
-    nested spans (depth + containment), so a per-phase breakdown sums to
-    ~the root span instead of double-counting parents."""
+    self_total_s}}. `self_total_s` subtracts the time spent in direct
+    child spans (those whose `parent` is the span's `id`; in files without
+    ids, depth + containment), so a per-phase breakdown sums to ~the root
+    span instead of double-counting parents."""
     spans = [e for e in events if e.get("ph") == "X"]
+    child_s: Dict[int, float] = {}
+    for c in spans:
+        p = c.get("args", {}).get("parent")
+        if p is not None:
+            child_s[p] = child_s.get(p, 0.0) + c["dur"] / 1e6
     out: Dict[str, Dict[str, float]] = {}
     for e in spans:
         rec = out.setdefault(e["name"], {"count": 0, "total_s": 0.0,
                                          "self_total_s": 0.0})
         rec["count"] += 1
         rec["total_s"] += e["dur"] / 1e6
-        child = 0.0
-        d = e.get("args", {}).get("depth")
-        if d is not None:
-            for c in spans:
-                if (c is not e and c.get("args", {}).get("depth") == d + 1
+        args = e.get("args", {})
+        d = args.get("depth")
+        if "id" in args:
+            child = child_s.get(args["id"], 0.0)
+        elif d is not None:
+            child = sum(c["dur"] / 1e6 for c in spans
+                        if c is not e
+                        and c.get("args", {}).get("depth") == d + 1
                         and c["ts"] >= e["ts"]
                         and c["ts"] + c.get("dur", 0.0)
-                        <= e["ts"] + e["dur"]):
-                    child += c["dur"] / 1e6
+                        <= e["ts"] + e["dur"])
+        else:
+            child = 0.0
         rec["self_total_s"] += max(e["dur"] / 1e6 - child, 0.0)
     for rec in out.values():
         rec["mean_s"] = rec["total_s"] / max(rec["count"], 1)

@@ -11,6 +11,14 @@ region, so enabling it cannot perturb a single compiled program, and with
 shared `NULL_TRACER` no-op — zero allocations, zero behavioural change
 (`tests/test_telemetry.py` pins summaries bitwise-identical on vs off).
 
+An enabled tracer also opens a `jax.profiler.TraceAnnotation` named
+``PROFILER_PREFIX + name`` around every span, with the span's args as its
+keyword arguments. While a JAX profiler session runs (`jax_profile`, or any
+`jax.profiler.start_trace`), each span is then a host event in the same
+profile as the device's operations, on one clock with them; otherwise the
+annotation is a no-op. Each span records its own `id`, the `parent` span
+open on the same thread, and its `depth` on that thread.
+
 The front door is `ExecSpec(trace=TraceConfig(enabled=True, path=...))`:
 `Simulator`, `StreamRunner`, `train_stream_sac/ppo`, and the serving
 backend all resolve the SAME `TraceConfig` to the SAME `Tracer` (live
@@ -34,8 +42,13 @@ import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
+import jax
+
 #: schema version stamped into every trace file (bump on breaking changes)
 TRACE_SCHEMA_VERSION = 1
+
+#: name prefix of every span's annotation in a JAX profiler trace
+PROFILER_PREFIX = "eat:"
 
 
 @dataclass(frozen=True)
@@ -55,7 +68,8 @@ class TraceConfig:
       the result summary/sweep rows.
     * ``profile_iters`` — decisions timed by the profiler probe.
     * ``jax_profiler_dir`` — opt-in `jax.profiler.start_trace` capture
-      directory (device-side profile alongside the host-span trace).
+      directory: one profile holding the device's operations and every
+      span (as ``PROFILER_PREFIX + name`` host events) on one clock.
     """
     enabled: bool = False
     path: str = "trace.json"
@@ -102,20 +116,24 @@ NULL_TRACER = NullTracer()
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "cat", "args", "t0", "depth")
+    __slots__ = ("tracer", "name", "cat", "args", "t0", "id", "parent",
+                 "depth", "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: Dict):
         self.tracer, self.name, self.cat, self.args = tracer, name, cat, args
 
     def __enter__(self):
-        self.depth = self.tracer._enter()
+        self.id, self.parent, self.depth = self.tracer._enter()
+        self.annotation = jax.profiler.TraceAnnotation(
+            PROFILER_PREFIX + self.name, **self.args)
+        self.annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self.t0
-        self.tracer._exit(self.name, self.cat, self.t0, dur, self.depth,
-                          self.args)
+        self.annotation.__exit__(*exc)
+        self.tracer._exit(self, dur)
         return False
 
 
@@ -135,7 +153,8 @@ class Tracer:
         self.events: List[Dict[str, Any]] = []
         self._t0 = time.perf_counter()
         self._epoch = time.time()
-        self._depth = 0
+        self._ids = 0
+        self._thread = threading.local()     # .stack: ids of open spans
         self._lock = threading.Lock()
         self._pid = os.getpid()
 
@@ -144,18 +163,31 @@ class Tracer:
         """Context manager: one complete ("X") event on exit."""
         return _Span(self, name, cat, args)
 
-    def _enter(self) -> int:
-        with self._lock:
-            d = self._depth
-            self._depth += 1
-        return d
+    def _stack(self) -> List[int]:
+        stack = getattr(self._thread, "stack", None)
+        if stack is None:
+            stack = self._thread.stack = []
+        return stack
 
-    def _exit(self, name, cat, t0, dur, depth, args) -> None:
-        ev = {"name": name, "cat": cat, "ph": "X",
-              "ts": (t0 - self._t0) * 1e6, "dur": dur * 1e6,
-              "pid": self._pid, "tid": 0, "args": dict(args, depth=depth)}
+    def _enter(self):
+        """(id, parent id or None, depth) of a span opening on this
+        thread."""
         with self._lock:
-            self._depth -= 1
+            self._ids += 1
+            sid = self._ids
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        stack.append(sid)
+        return sid, parent, len(stack) - 1
+
+    def _exit(self, span: _Span, dur: float) -> None:
+        self._stack().pop()
+        ev = {"name": span.name, "cat": span.cat, "ph": "X",
+              "ts": (span.t0 - self._t0) * 1e6, "dur": dur * 1e6,
+              "pid": self._pid, "tid": 0,
+              "args": dict(span.args, depth=span.depth, id=span.id,
+                           parent=span.parent)}
+        with self._lock:
             self.events.append(ev)
 
     def instant(self, name: str, cat: str = "phase", **args) -> None:
@@ -240,12 +272,10 @@ class jax_profile:
 
     def __enter__(self):
         if self._dir:
-            import jax
             jax.profiler.start_trace(self._dir)
         return self
 
     def __exit__(self, *exc):
         if self._dir:
-            import jax
             jax.profiler.stop_trace()
         return False

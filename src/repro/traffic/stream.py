@@ -547,41 +547,44 @@ class StreamRunner:
             tr.counter("pending_retry", float(self.pending_retry()),
                        window=w)
 
-        tr.counter("backlog", float(n_left.sum()), window=w)
-        rec = {k: np.asarray(v) for k, v in stats.items()}
-        if self.placement is not None:
-            # per-model tallies are placement telemetry, not window-ledger
-            # rows: fold them here and keep the aggregator's schema fixed
-            self._pm_sched += rec.pop("n_sched_m").sum(axis=0)
-            self._pm_reload += rec.pop("n_reload_m").sum(axis=0)
-        rec["n_injected"] = n_injected
-        rec["n_dropped"] = n_dropped
-        rec["n_carried"] = n_carried
-        rec["n_leftover"] = n_left.astype(np.int64)
-        if self.faults is not None:
-            rec["n_retried"] = n_retried
-            rec["n_failed_dropped"] = n_fail_drop
-            rec["n_readmitted"] = n_readmit
-        self.agg.update(rec)
-        n_sched_w = int(rec["n_sched"].sum())
-        record = {
-            "window": w,
-            "injected": int(n_injected.sum()),
-            "carried": int(n_carried.sum()),
-            "scheduled": n_sched_w,
-            "dropped": int(n_dropped.sum()),
-            "leftover": int(n_left.sum()),
-            "mean_elapsed": float(np.mean(rec["elapsed"])),
-            "mean_latency": float(rec["sum_resp"].sum() / max(n_sched_w, 1)),
-            "episode_return_mean": float(np.mean(np.asarray(
-                res.metrics["episode_return"]))),
-        }
-        if self.faults is not None:
-            record["failed"] = int(rec["n_failed"].sum())
-            record["retried"] = int(n_retried.sum())
-            record["failed_dropped"] = int(n_fail_drop.sum())
-            record["pending_retry"] = self.pending_retry()
-        self.per_window.append(record)
+        with tr.span("window_record", cat="stream", window=w):
+            tr.counter("backlog", float(n_left.sum()), window=w)
+            rec = {k: np.asarray(v) for k, v in stats.items()}
+            if self.placement is not None:
+                # per-model tallies are placement telemetry, not
+                # window-ledger rows: fold them here and keep the
+                # aggregator's schema fixed
+                self._pm_sched += rec.pop("n_sched_m").sum(axis=0)
+                self._pm_reload += rec.pop("n_reload_m").sum(axis=0)
+            rec["n_injected"] = n_injected
+            rec["n_dropped"] = n_dropped
+            rec["n_carried"] = n_carried
+            rec["n_leftover"] = n_left.astype(np.int64)
+            if self.faults is not None:
+                rec["n_retried"] = n_retried
+                rec["n_failed_dropped"] = n_fail_drop
+                rec["n_readmitted"] = n_readmit
+            self.agg.update(rec)
+            n_sched_w = int(rec["n_sched"].sum())
+            record = {
+                "window": w,
+                "injected": int(n_injected.sum()),
+                "carried": int(n_carried.sum()),
+                "scheduled": n_sched_w,
+                "dropped": int(n_dropped.sum()),
+                "leftover": int(n_left.sum()),
+                "mean_elapsed": float(np.mean(rec["elapsed"])),
+                "mean_latency": float(rec["sum_resp"].sum()
+                                      / max(n_sched_w, 1)),
+                "episode_return_mean": float(np.mean(np.asarray(
+                    res.metrics["episode_return"]))),
+            }
+            if self.faults is not None:
+                record["failed"] = int(rec["n_failed"].sum())
+                record["retried"] = int(n_retried.sum())
+                record["failed_dropped"] = int(n_fail_drop.sum())
+                record["pending_retry"] = self.pending_retry()
+            self.per_window.append(record)
         self.window += 1
         return WindowResult(window=w, stats=rec, record=record,
                             metrics=res.metrics,

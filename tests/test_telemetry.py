@@ -9,6 +9,7 @@ round-trips; (4) `LatencyHistogram.percentile` boundary semantics
 compilation out of the serving backend's timed region.
 """
 import json
+import threading
 import time
 
 import jax
@@ -25,6 +26,7 @@ from repro.telemetry import (DECISION_EDGES, NULL_TRACER, DecisionProfile,
                              profile_policy, reset_tracers, span_durations,
                              tracer_for, validate_trace)
 from repro.telemetry.schema import KNOWN_SPANS, validate_events
+from repro.telemetry.trace import PROFILER_PREFIX
 
 ECFG = EV.EnvConfig(num_servers=4, max_tasks=8)
 TCFG = WorkloadTraceConfig(num_tasks=8, arrival_rate=2.0, max_servers=4)
@@ -120,6 +122,118 @@ def test_span_durations_and_counters(tmp_path):
     assert d["outer"]["total_s"] >= d["inner"]["total_s"]
     # self time excludes the contained child span
     assert d["outer"]["self_total_s"] <= d["outer"]["total_s"]
+
+
+class _Annotations:
+    """Stands in for `jax.profiler.TraceAnnotation`: records each
+    annotation's name, kwargs, and entries/exits in order."""
+
+    def __init__(self):
+        self.log = []
+        outer = self
+
+        class Annotation:
+            def __init__(self, name, **kwargs):
+                self.name = name
+                outer.log.append(("new", name, kwargs))
+
+            def __enter__(self):
+                outer.log.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                outer.log.append(("exit", self.name))
+
+        self.cls = Annotation
+
+
+def test_enabled_tracer_annotates_every_span(monkeypatch, tmp_path):
+    """Each span opens one profiler annotation, named under the program
+    prefix and carrying the span's args, around the span's own timing."""
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann.cls)
+    tr = tracer_for(TraceConfig(enabled=True, path=str(tmp_path / "t.json")))
+    with tr.span("window", cat="stream", window=3, backend="fused"):
+        with tr.span("window_seam", cat="stream"):
+            pass
+    assert PROFILER_PREFIX == "eat:"
+    assert ann.log == [
+        ("new", "eat:window", {"window": 3, "backend": "fused"}),
+        ("enter", "eat:window"),
+        ("new", "eat:window_seam", {}), ("enter", "eat:window_seam"),
+        ("exit", "eat:window_seam"), ("exit", "eat:window")]
+    # the JSON events keep the span's own args, not the annotation's
+    assert [e["args"].get("window") for e in tr.events] == [None, 3]
+
+
+def test_null_tracer_builds_no_annotation(monkeypatch):
+    """Tracing off: neither a span of the shared no-op tracer nor a whole
+    untraced run builds a single program annotation."""
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann.cls)
+    with NULL_TRACER.span("window", cat="stream", window=0):
+        pass
+    assert NULL_TRACER.span("decision", step=1) is NULL_TRACER.span("x")
+    _run(api.ExecSpec(), key=5)
+    assert [n for _, n, *_ in ann.log if n.startswith(PROFILER_PREFIX)] == []
+
+
+def test_parent_and_depth_follow_each_thread(tmp_path):
+    """Two threads with interleaved spans: each span's parent is the span
+    open on its own thread, and its depth counts only that thread's."""
+    tr = tracer_for(TraceConfig(enabled=True, path=str(tmp_path / "t.json")))
+    both_open = threading.Barrier(2)
+    inner_done = threading.Barrier(2)
+
+    def work(tag):
+        with tr.span(f"outer_{tag}"):
+            both_open.wait()
+            with tr.span(f"inner_{tag}"):
+                with tr.span(f"leaf_{tag}"):
+                    pass
+            inner_done.wait()
+
+    threads = [threading.Thread(target=work, args=(t,)) for t in "ab"]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    ev = {e["name"]: e["args"] for e in tr.events}
+    assert len({a["id"] for a in ev.values()}) == 6
+    for t in "ab":
+        assert ev[f"outer_{t}"]["parent"] is None
+        assert ev[f"outer_{t}"]["depth"] == 0
+        assert ev[f"inner_{t}"]["parent"] == ev[f"outer_{t}"]["id"]
+        assert ev[f"inner_{t}"]["depth"] == 1
+        assert ev[f"leaf_{t}"]["parent"] == ev[f"inner_{t}"]["id"]
+        assert ev[f"leaf_{t}"]["depth"] == 2
+    # self time subtracts a span's own children, not another thread's
+    d = span_durations(tr.events)
+    for t in "ab":
+        outer = [e for e in tr.events if e["name"] == f"outer_{t}"][0]
+        inner = [e for e in tr.events if e["name"] == f"inner_{t}"][0]
+        assert d[f"outer_{t}"]["self_total_s"] == pytest.approx(
+            (outer["dur"] - inner["dur"]) / 1e6)
+
+
+def test_spans_reach_a_jax_profile_with_their_args(tmp_path):
+    """In a JAX profiler session the program's spans are host events of the
+    profile, `eat:<name>`, their args as event stats."""
+    import glob
+
+    from jax.profiler import ProfileData
+    tr = tracer_for(TraceConfig(enabled=True, path=str(tmp_path / "t.json")))
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        with tr.span("decode", cat="serving", arch="a", steps=7):
+            jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
+                        recursive=True)
+    host = ProfileData.from_file(path).find_plane_with_name("/host:CPU")
+    got = [dict(ev.stats) for line in host.lines for ev in line.events
+           if ev.name == "eat:decode"]
+    assert got == [{"arch": "a", "steps": 7}]
 
 
 # ------------------------------------------------------------ metrics

@@ -54,8 +54,12 @@ def _sds(sharding, shape, dtype=F32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(compiled, name):
+    """A Mosaic kernel is there, under its stable HLO instruction name
+    (the `pallas_call`'s `name=`), which a profile's op names carry."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"%{name}." in text or f"%{name} " in text
 
 
 @pytest.mark.parametrize("faults", [False, True])
@@ -75,7 +79,7 @@ def test_env_step_compiles(one_chip, E, B, faults):
           if faults else {})
     step = jax.jit(lambda *a, **k: env_step_pallas(cfg, *a, **k,
                                                    interpret=False))
-    _assert_kernel(step.lower(*args, **kw).compile())
+    _assert_kernel(step.lower(*args, **kw).compile(), "env_step_pallas")
 
 
 @pytest.mark.parametrize("B", [1, 64])
@@ -95,7 +99,7 @@ def test_denoiser_chain_compiles(one_chip, sampler, B):
         s(B, A), s(K, B, A), s(B, Fs), s(K, T_DIM), s(K), s(K), s(K),
         s(D, HIDDEN), s(HIDDEN), s(HIDDEN, HIDDEN), s(HIDDEN),
         s(HIDDEN, A), s(A)).compile()
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "denoiser_chain")
 
 
 def test_denoiser_step_compiles(one_chip):
@@ -106,7 +110,7 @@ def test_denoiser_step_compiles(one_chip):
     step = jax.jit(lambda *a: denoiser_step(*a, interpret=False))
     _assert_kernel(step.lower(
         s(64, D), s(D, HIDDEN), s(HIDDEN), s(HIDDEN, HIDDEN), s(HIDDEN),
-        s(HIDDEN, A), s(A)).compile())
+        s(HIDDEN, A), s(A)).compile(), "denoiser_step")
 
 
 def test_fused_rollout_compiles_with_both_kernels(one_chip, monkeypatch):
@@ -114,7 +118,8 @@ def test_fused_rollout_compiles_with_both_kernels(one_chip, monkeypatch):
     the ddim actor's chain kernel, vmapped over B=256 streams inside the
     policy (`actors.program.ActorProgram.vmapped`). The platform checks
     in the kernel wrappers see this host's CPU, so the test tells them
-    they are on a TPU."""
+    they are on a TPU. The policy's and the env step's ops carry their
+    named scopes in the compiled program's metadata."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     sc = SC.paper_scenarios()[1]            # the paper's E=8 cluster
     ecfg, B = sc.ecfg, 256
@@ -130,4 +135,7 @@ def test_fused_rollout_compiles_with_both_kernels(one_chip, monkeypatch):
         ecfg, tr, rp.policy, p, k, num_steps=4))
     compiled = run.lower(on(traces), on(jax.eval_shape(lambda: rp.params)),
                          on(keys)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 2
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "%env_step_pallas" in text and "%denoiser_chain" in text
+    assert "/vmap(policy)/" in text and "/env_step/" in text
