@@ -129,7 +129,9 @@ def _batch_rollout_fused(ecfg: EV.EnvConfig, traces: Dict, policy: Policy,
                          impl) -> RolloutResult:
     """Scan over decision steps; each step advances all B envs through one
     fused decision op (`kernels.env_step.ops.env_step_fused`). Bitwise-equal
-    to `vmap(rollout_episode)` — the per-env op sequence is identical."""
+    to `vmap(rollout_episode)` — the per-env op sequence is identical.
+    The fused op also freezes the state and queue of an env whose episode
+    is over."""
     T = int(num_steps) if num_steps else ecfg.max_steps
     B = keys.shape[0]
     state0 = _batch_reset(ecfg, B) if init_state is None else init_state
@@ -148,11 +150,7 @@ def _batch_rollout_fused(ecfg: EV.EnvConfig, traces: Dict, policy: Policy,
         action, extras = vpolicy(params, k_act, traces, state, obs)
         with jax.named_scope("env_step"):
             nstate, nq, nobs, r, d = EK.env_step_fused(
-                ecfg, statics, state, action, q, impl=impl)
-        nstate = jax.tree_util.tree_map(
-            lambda n, o: jnp.where(_bcast(done, n), o, n), nstate, state)
-        nq = jax.tree_util.tree_map(
-            lambda n, o: jnp.where(_bcast(done, n), o, n), nq, q)
+                ecfg, statics, state, action, q, frozen=done, impl=impl)
         nobs = jnp.where(_bcast(done, nobs), obs, nobs)
         r = jnp.where(done, 0.0, r)
         valid = ~done

@@ -16,6 +16,7 @@ from repro.core import env as EV
 from repro.core import rollout as RO
 from repro.core import scenarios as SC
 from repro.core.workload import TraceConfig, make_trace, make_trace_batch
+from repro.faults import FaultSpec
 from repro.kernels.env_step import ops as EK
 from repro.traffic import (PoissonArrivals, ProcessTaskSource, StreamConfig,
                            run_stream)
@@ -225,21 +226,33 @@ def _assert_scenario_parity(sc, num_steps):
 
 
 # ---------------------------------------------------------------- streaming
-def test_fused_stream_matches_unfused_across_seams():
+@pytest.mark.parametrize("faults", [None, FaultSpec(
+    seed=2, mtbf=60.0, mttr=15.0, straggler_prob=0.3, straggler_factor=3.0)],
+    ids=["fault-free", "faults"])
+@pytest.mark.parametrize("impl", ["ref", "pallas"])
+def test_fused_stream_matches_unfused_across_seams(impl, faults):
     """Multi-window streaming (carried gangs relabelled into [K, K+E),
     backlog carry, clock rebase) is bitwise-identical on the fused engine:
-    same summaries, same per-window ledgers, same final carry state."""
+    same summaries, same per-window ledgers, same final carry state. Also
+    with the Pallas kernel (the envs on its lane axis, a finished env's
+    state frozen inside it), over 3 streams (lanes that fill no tile) and
+    with fault columns."""
     ecfg = EV.EnvConfig(num_servers=4, max_tasks=16, queue_window=4,
                         max_steps=64)
     tc = TraceConfig(num_tasks=16, arrival_rate=0.3, max_servers=4)
 
-    def run(fused):
+    def fused(ecfg, traces, policy, params, keys, **kw):
+        return RO.batch_rollout(ecfg, traces, policy, params, keys,
+                                fused=True, fused_impl=impl, **kw)
+
+    def run(is_fused):
         src = ProcessTaskSource(PoissonArrivals(0.3), tc,
-                                jax.random.PRNGKey(0), num_streams=2)
+                                jax.random.PRNGKey(0), num_streams=3)
         return run_stream(ecfg, RO.fifo_policy(ecfg), {}, src,
                           jax.random.PRNGKey(1),
-                          StreamConfig(num_windows=5, num_streams=2,
-                                       fused=fused))
+                          StreamConfig(num_windows=5, num_streams=3,
+                                       fused=is_fused, faults=faults),
+                          rollout_fn=fused if is_fused else None)
 
     a, b = run(False), run(True)
     assert a.summary == b.summary

@@ -63,17 +63,22 @@ def _assert_kernel(compiled, name):
 
 
 @pytest.mark.parametrize("faults", [False, True])
-@pytest.mark.parametrize("B", [1, 256])
+@pytest.mark.parametrize("B", [1, 130, 256, 1024])
 @pytest.mark.parametrize("E", [8, 32])
 def test_env_step_compiles(one_chip, E, B, faults):
+    """The state, queue and constants batch-first, transposed inside the
+    kernel; the action lane-major. B=1 one narrow block, B=130 two
+    padded blocks of 128, B=256 the benchmark's, B=1024 eight steps (with
+    the fault columns at E=32 too)."""
     cfg = EV.EnvConfig(num_servers=E)
     K, l, A = cfg.max_tasks, cfg.queue_window, cfg.action_dim
-    s = lambda *dims, dt=F32: _sds(one_chip, (B,) + dims, dt)  # noqa: E731
-    args = [s(1), s(E), s(E, dt=I32), s(E, dt=I32), s(E, dt=I32),
-            s(K, dt=I32), s(K), s(K), s(K, dt=I32), s(K), s(K, dt=I32),
+    s = lambda *dims, dt=F32: _sds(one_chip, dims + (B,), dt)  # noqa: E731
+    r = lambda n, dt=F32: _sds(one_chip, (B, n), dt)  # noqa: E731
+    args = [s(1), r(E), r(E, I32), r(E, I32), r(E, I32),
+            r(K, I32), r(K), r(K), r(K, I32), r(K), r(K, I32),
             s(1, dt=I32),
-            s(K), s(K, dt=I32), s(K, dt=I32), s(K), s(K), s(K), s(K),
-            s(A), s(l, dt=I32), s(l, dt=I32), s(K, dt=I32)]
+            r(K), r(K, I32), r(K, I32), r(K), r(K), r(K), r(K),
+            s(A), r(l, I32), r(l, I32), r(K, I32), s(1, dt=I32)]
     F = FaultSpec().max_down_events
     kw = (dict(fds=s(E, F), fde=s(E, F), fslow=s(E), fcold=s(1))
           if faults else {})
