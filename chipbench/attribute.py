@@ -6,8 +6,8 @@ time went, by the program's own spans.
 
 The run is `run.py`'s traced run: the measured window with the program's
 host tracer on, then a profiled extra window, then the check. The profile
-is reduced by `chipbench/spantrace.py` in place of `chipbench/devtrace.py`
-alone, so each idle gap is named by the innermost program span around it.
+is reduced by `chipbench/spantrace.py`, so each idle gap is named by the
+innermost program span around it.
 It prints one JSON line:
 
 * `e2e_traced`: the measured window's end-to-end metrics with the tracer
@@ -30,42 +30,19 @@ T_PROCESS = time.perf_counter()
 import argparse  # noqa: E402
 import importlib  # noqa: E402
 import json  # noqa: E402
-import shutil  # noqa: E402
 import sys  # noqa: E402
-import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
-from chipbench import devtrace, harness, spantrace  # noqa: E402
+from chipbench import harness  # noqa: E402
 from chipbench.run import Cell, device_or_exit  # noqa: E402
 
 SPAN_METRICS = ("seam_idle_ms", "policy_device_us", "decision_idle_ms",
                 "decode_idle_ms_per_token", "model_load_ms")
 SCOPES = ("policy", "env_step")
 KERNELS = ("env_step_pallas", "denoiser_chain", "denoiser_step")
-
-
-class SpanContext(harness.Context):
-    """`harness.Context` whose profiled window is reduced with the
-    program's spans (`spantrace`)."""
-
-    def profiled(self, fn):
-        import jax
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        d = tempfile.mkdtemp(prefix="chipbench-trace-")
-        try:
-            jax.profiler.start_trace(d, profiler_options=opts)
-            try:
-                with jax.profiler.TraceAnnotation(devtrace.MARK + "profiled"):
-                    fn()
-            finally:
-                jax.profiler.stop_trace()
-            self.device = spantrace.reduce(*spantrace.load(d))
-        finally:
-            shutil.rmtree(d, ignore_errors=True)
 
 
 def report(cell, ctx, out) -> dict:
@@ -94,10 +71,10 @@ def run(argv=None):
     cell = Cell(bench, args.workload)
     devs = device_or_exit(cell.chips)
     harness.setup_jax()
-    ctx = SpanContext(cell, args.seed, args.seconds, True, devs, T_PROCESS)
+    ctx = harness.Context(cell, args.seed, args.seconds, True, devs,
+                          T_PROCESS)
     ctx.mark("devices found")
-    driver = importlib.import_module(
-        f"chipbench.drivers.{cell.config['driver']}")
+    driver = importlib.import_module(f"chipbench.drivers.{cell.driver}")
     return cell, ctx, driver.run(ctx)
 
 

@@ -3,7 +3,7 @@
 control's, for one cell, over many seeds, in one process.
 
     python3 chipbench/control.py --workload <name> --seeds <n> [--first <seed>]
-        [--seconds <s>] [--out chiprun_out/control_<name>.json]
+        [--seconds <s>] [--faults half_batch] [--out chiprun_out/control_<name>.json]
 
 For each seed it runs the cell once as the benchmark does (a short window
 at the cell's own load, the check's usual sample) and records the numbers
@@ -11,9 +11,13 @@ the check compares; then runs it again with the control in the program's
 place: the plain reference computed in the next precision below the one
 the configuration states (`reference/scheduler.py` `arith(...,
 control=True)`: environment and actor activations in bfloat16;
-`reference/qwen2.py`: float8 weights and matmul inputs). A limit lies
-above the program's readings and below the control's. The benchmark's own
-runs never run the control.
+`reference/qwen2.py`: float8 weights and matmul inputs;
+`reference/sac.py`: every value in bfloat16). `--faults` names faults a
+driver can plant in the reference put in the program's place (the train
+driver: `half_batch`, each update over the first half of its batch), run
+on the same seeds as the control. A limit lies above the program's readings
+and below the control's and the faults'. The benchmark's own runs never run
+the control or a fault.
 """
 from __future__ import annotations
 
@@ -37,6 +41,8 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=10.0)
     ap.add_argument("--controls", type=int, default=3,
                     help="seeds (the first ones) that also run the control")
+    ap.add_argument("--faults", nargs="*", default=[],
+                    help="faults to plant on the control's seeds")
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
     sys.path.insert(0, str(ROOT))
@@ -47,12 +53,12 @@ def main(argv=None) -> int:
     devs = device_or_exit(cell.chips)
     harness.setup_jax()
     import importlib
-    driver = importlib.import_module(
-        f"chipbench.drivers.{cell.config['driver']}")
+    driver = importlib.import_module(f"chipbench.drivers.{cell.driver}")
     rows = []
     for i in range(args.seeds):
         seed = args.first + i
-        for control in ((False, True) if i < args.controls else (False,)):
+        modes = [False] + ([True] + args.faults if i < args.controls else [])
+        for control in modes:
             ctx = harness.Context(cell, seed, args.seconds, False, devs,
                                   time.perf_counter())
             ctx.control = control

@@ -51,6 +51,30 @@ def greedy_ops(E: int, K: int, l: int, grid: int = 9) -> int:
     return (1 + grid * l) * env_step_ops(E, K, l)
 
 
+# -------------------------------------------------------------- learner
+def critic_ops(obs_dim: int, A: int, hidden: int) -> int:
+    """One forward pass of a critic, the MLP [obs_dim + A, hidden, hidden,
+    1], for one transition."""
+    return 2 * ((obs_dim + A) * hidden + hidden * hidden + hidden)
+
+
+def sac_update_ops(batch: int, rows: int, cols: int, A: int, d_attn: int,
+                   hidden: int, t_dim: int, T: int, critic_hidden: int) -> int:
+    """The matrix products of one SAC update over `batch` transitions, each
+    pass once (no recompute). A backward pass that yields the weights'
+    gradients and the inputs' costs twice its forward; one that yields the
+    inputs' alone, once. The target: the actor at s' and two target critics
+    forward. The critics: forward and backward each. The actor: the actor
+    forward and backward, and the two updated critics forward and back to
+    their action input."""
+    actor = actor_ops(rows, cols, A, d_attn, hidden, t_dim, T)
+    critic = critic_ops(rows * cols, A, critic_hidden)
+    target = actor + 2 * critic
+    critics = 2 * 3 * critic
+    actor_loss = 3 * actor + 2 * 2 * critic
+    return batch * (target + critics + actor_loss)
+
+
 # --------------------------------------------------------------- serving
 def _layer(m: Dict) -> Tuple[int, int]:
     """(matrix parameters, all parameters) of one transformer layer."""

@@ -1,12 +1,16 @@
 """What every cell shares: set-up of JAX and the program, the profiled
 window, the device's memory peak, and the result line.
 
-A driver (`chipbench/drivers/<name>.py`) defines `run(ctx) -> Outcome`. It
+A driver (`chipbench/drivers/<name>.py`, named by the traffic file's
+`driver` or else by the configuration's) defines `run(ctx) -> Outcome`. It
 builds the cell from the configuration and traffic files, warms every shape
-the window uses, calls `ctx.start_window()`, runs the measured window,
-reads `ctx.memory_peak()`, and then checks what the window produced against
-the configuration's plain reference. In a `--trace 1` run it wraps a short
-extra window in `ctx.profiled(...)`.
+the window uses, calls `ctx.start_window()`, runs the measured window, reads
+`ctx.memory_peak()`, and then checks what the window produced against the
+configuration's plain reference. In a `--trace 1` run it wraps a short
+extra window in `ctx.profiled(...)`, or between `ctx.profile_begin()` and
+`ctx.profile_end()` where the program's own loop runs it. The profile is
+reduced by `spantrace`: `devtrace`'s numbers, with each idle gap named by
+the program's innermost span and the device's idle and busy time per span.
 """
 from __future__ import annotations
 
@@ -21,7 +25,7 @@ from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Callable, Dict, List, Optional, Tuple
 
-from chipbench import devtrace
+from chipbench import devtrace, spantrace
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -58,13 +62,14 @@ class Context:
         self.t_process = t_process
         self.setup_s: Optional[float] = None
         self.peak_bytes: Optional[int] = None
-        self.device: Optional[devtrace.DeviceTrace] = None
+        self.device: Optional[spantrace.SpanTrace] = None
         self.peaks = peaks or peaks_for(devs[0].device_kind)
         # the control: the reference, in the next precision below the one
         # the configuration states, checked in the program's place
         # (chipbench/control.py); never set in a benchmark run
         self.control = False
         self.marks: List[Tuple[str, float]] = []
+        self._profile: Optional[Tuple[str, object]] = None
 
     def mark(self, what: str) -> None:
         """Note a step of set-up, in seconds from process start."""
@@ -86,18 +91,37 @@ class Context:
 
     def profiled(self, fn: Callable[[], None]) -> None:
         """Run `fn` under the JAX profiler, reduce the trace, delete it."""
+        self.profile_begin()
+        try:
+            fn()
+        finally:
+            self.profile_end()
+
+    def profile_begin(self) -> None:
+        """Start the JAX profiler and the `bench:profiled` window."""
         import jax
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         d = tempfile.mkdtemp(prefix="chipbench-trace-")
         try:
             jax.profiler.start_trace(d, profiler_options=opts)
-            try:
-                with jax.profiler.TraceAnnotation(devtrace.MARK + "profiled"):
-                    fn()
-            finally:
-                jax.profiler.stop_trace()
-            self.device = devtrace.reduce(devtrace.load(d))
+        except BaseException:
+            shutil.rmtree(d, ignore_errors=True)
+            raise
+        mark = jax.profiler.TraceAnnotation(devtrace.MARK + "profiled")
+        mark.__enter__()
+        self._profile = (d, mark)
+
+    def profile_end(self) -> None:
+        """Close the window, stop the profiler, reduce the trace with the
+        program's spans (`spantrace`) and delete it."""
+        import jax
+        d, mark = self._profile
+        self._profile = None
+        try:
+            mark.__exit__(None, None, None)
+            jax.profiler.stop_trace()
+            self.device = spantrace.reduce(*spantrace.load(d))
         finally:
             shutil.rmtree(d, ignore_errors=True)
 
@@ -162,7 +186,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devs,
     setup_jax()
     ctx = Context(cell, seed, seconds, trace, devs, t_process)
     ctx.mark("devices found")
-    driver = importlib.import_module(f"chipbench.drivers.{cell.config['driver']}")
+    driver = importlib.import_module(f"chipbench.drivers.{cell.driver}")
     out = driver.run(ctx)
     return ctx, out
 

@@ -5,9 +5,11 @@
 
 Run it from the root of a checkout. Everything about the cell is data,
 found by name: the entry of `workloads` in `BENCHMARK.json`, the
-configuration file it names (its `driver` key picks `chipbench/drivers/
-<driver>.py`), the traffic file `chipbench/traffic/<traffic>.json`, and
-one reader `chipbench/metrics/<metric>.py` per per-layer metric.
+configuration file it names, the traffic file
+`chipbench/traffic/<traffic>.json`, the driver `chipbench/drivers/
+<driver>.py` (named by the traffic file's `driver` key, or else by the
+configuration's), and one reader `chipbench/metrics/<metric>.py` per
+per-layer metric.
 
 With `--trace 0` the result carries the cell's end-to-end metrics, with
 `--trace 1` its per-layer metrics, device busy time and a breakdown from a
@@ -49,6 +51,7 @@ class Cell:
         self.config = json.loads((root / self.config_entry["file"]).read_text())
         self.traffic = json.loads((root / "chipbench" / "traffic"
                                    / f"{self.entry['traffic']}.json").read_text())
+        self.driver = self.traffic.get("driver", self.config["driver"])
         applies = lambda m: workload in m.get("workloads", [workload])  # noqa: E731
         self.end_to_end = [m for m in bench["end_to_end"] if applies(m)]
         self.per_layer = [m for m in bench["per_layer"] if applies(m)]

@@ -13,6 +13,7 @@ duration are the same numbers. It adds:
   name, a benchmark mark as `bench:<name>`);
 * `idle_by_span`: idle seconds per innermost span, each gap cut at the
   spans' edges, so a gap that covers two spans is divided between them;
+  `busy_by_span` the same for the device's busy time;
 * `span_calls`: per program span started in the window, its count and the
   sums of its numeric args (the `steps` of `decode`);
 * each device op's named scope, from the `tf_op` stat of its event
@@ -123,6 +124,7 @@ def clock_offset(launches: List[Launch], plane: str) -> float:
 @dataclass
 class SpanTrace(devtrace.DeviceTrace):
     idle_by_span: Dict[str, float] = field(default_factory=dict)
+    busy_by_span: Dict[str, float] = field(default_factory=dict)
     span_calls: Dict[str, Dict[str, float]] = field(default_factory=dict)
     scope_op_seconds: Dict[str, float] = field(default_factory=dict)
     scope_op_calls: Dict[str, int] = field(default_factory=dict)
@@ -168,16 +170,7 @@ def reduce(events: List[Event], spans: List[Span], scopes: Scopes,
                             lo, hi)
     edges = [lo] + [x for iv in merged for x in iv] + [hi]
     gaps = [(s, e) for s, e in zip(edges[0::2], edges[1::2]) if e > s]
-    idle: Dict[str, float] = {}
-    j = 0
-    for s, e in gaps:             # both in time order: one pass
-        while segs[j][1] <= s:
-            j += 1
-        k = j
-        while k < len(segs) and segs[k][0] < e:
-            a, b, name = segs[k]
-            idle[name] = idle.get(name, 0.0) + (min(b, e) - max(a, s)) * 1e-9
-            k += 1
+    idle = by_segment(gaps, segs)
     calls: Dict[str, Dict[str, float]] = {}
     for sp in spans:              # called inside the window, by the host
         if not lo <= sp.start < hi:
@@ -202,9 +195,27 @@ def reduce(events: List[Event], spans: List[Span], scopes: Scopes,
     return SpanTrace(window_s=base.window_s, busy_s=base.busy_s,
                      devices=base.devices, op_seconds=base.op_seconds,
                      op_calls=base.op_calls, idle_gaps=labelled,
-                     idle_by_span=idle, span_calls=calls,
+                     idle_by_span=idle, busy_by_span=by_segment(merged, segs),
+                     span_calls=calls,
                      scope_op_seconds=sc_s, scope_op_calls=sc_n,
                      clock_offset_s=off * 1e-9)
+
+
+def by_segment(intervals: List[Tuple[float, float]],
+               segs: List[Tuple[float, float, str]]) -> Dict[str, float]:
+    """Seconds of the intervals under each segment's name; both lists in
+    time order, the intervals disjoint, so one pass."""
+    out: Dict[str, float] = {}
+    j = 0
+    for s, e in intervals:
+        while segs[j][1] <= s:
+            j += 1
+        k = j
+        while k < len(segs) and segs[k][0] < e:
+            a, b, name = segs[k]
+            out[name] = out.get(name, 0.0) + (min(b, e) - max(a, s)) * 1e-9
+            k += 1
+    return out
 
 
 def label(span: Span) -> str:

@@ -31,9 +31,18 @@ def tiny_cell(workload: str, bench: Optional[Dict] = None, root: Path = ROOT):
     cell = Cell(bench, workload, root)
     cfg = copy.deepcopy(cell.config)
     tr = dict(cell.traffic)
-    if cfg["driver"] == "sim":
+    if cell.driver == "sim":
         tr["streams"] = 4
         cfg["check"].update(streams=4, min_decisions=40)
+    elif cell.driver == "train":
+        # 4 streams, batches of 32 and 4 updates a round: round 0 passes
+        # the warm-up, rounds 1 and 2 collect with the actor
+        tr["streams"] = 4
+        tn = tr["train"] = copy.deepcopy(tr["train"])
+        tn["sac"].update(batch_size=32, warmup_steps=32,
+                         buffer_capacity=4096)
+        tn.update(max_updates_per_round=4, setup_updates=4, profile_updates=4)
+        tn["check"].update(updates=4, collect_streams=4, min_decisions=40)
     else:
         cfg.update(TINY_QWEN2)
         cfg["serving"].update(reduced=True, dtype="float32")
@@ -53,8 +62,7 @@ def tiny_run(cell, seed: int = 2 ** 31 + 12345, seconds: float = 0.5,
     ctx = harness.Context(cell, seed, seconds, trace, jax.devices(),
                           time.perf_counter(), peaks=CPU_PEAKS)
     ctx.control = control
-    driver = importlib.import_module(
-        f"chipbench.drivers.{cell.config['driver']}")
+    driver = importlib.import_module(f"chipbench.drivers.{cell.driver}")
     return ctx, driver.run(ctx)
 
 

@@ -40,3 +40,12 @@ def test_qwen2_counts_by_hand():
     ops, nbytes = costs.decode_cost(M, 6)
     assert ops == 2 * (2 * mats + 2 * 2 * 2 * 2 * 7) + 2 * 4 * 10
     assert nbytes == wbytes + 2 * 8 * 2 * 1 * 2 * 2
+
+
+def test_sac_update_ops_by_hand():
+    # critic over obs_dim=2, A=1, hidden=1: 2*((2+1)*1 + 1 + 1) = 10
+    assert costs.critic_ops(2, 1, 1) == 10
+    # actor (rows=1, cols=2, A=1, d_attn=1, hidden=1, t_dim=0, T=1) 44;
+    # target 44 + 20, critics 6*10, actor loss 3*44 + 4*10; batch 3
+    assert costs.sac_update_ops(3, 1, 2, 1, 1, 1, 0, 1, 1) == 3 * (
+        64 + 60 + 172)

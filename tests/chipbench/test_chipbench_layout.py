@@ -62,9 +62,9 @@ def test_every_name_has_its_files():
     b = bench()
     for w in b["workloads"]:
         cell = Cell(b, w["name"])
-        assert cell.config["driver"] in ("sim", "serve")
+        assert cell.driver in ("sim", "serve", "train")
         assert (ROOT / "chipbench" / "drivers"
-                / f"{cell.config['driver']}.py").is_file()
+                / f"{cell.driver}.py").is_file()
     for m in b["per_layer"]:
         assert callable(harness.reader(m["name"]).read)
 
@@ -98,6 +98,37 @@ def test_new_cell_is_found_by_name_alone(tmp_path):
     ctx, out = testing.tiny_run(cell, seconds=0.3)
     assert testing.correct(out)
     assert harness.reader("windows_run", tmp_path).read(ctx, out) >= 1
+
+
+def test_cell_that_names_its_driver_is_found_by_name_alone(tmp_path):
+    """A traffic file that names its driver runs that driver, whatever
+    the configuration's: a training cell on the sim cells' configuration,
+    added as data alone."""
+    shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    b = bench()
+    tr = json.loads((ROOT / "chipbench/traffic/poisson-0.10-b64-sac.json")
+                    .read_text())
+    tr["rate_per_stream"] = 0.12
+    (tmp_path / "chipbench/traffic/poisson-0.12-b64-sac.json").write_text(
+        json.dumps(tr))
+    b["workloads"].append({"name": "train-paper8-sac-0.12",
+                           "config": "paper-8srv",
+                           "traffic": "poisson-0.12-b64-sac", "chips": 1,
+                           "why": "a test cell"})
+    for m in b["end_to_end"]:
+        if m["name"] == "train_transitions_per_s":
+            m["workloads"].append("train-paper8-sac-0.12")
+    cell = Cell(b, "train-paper8-sac-0.12", tmp_path)
+    assert cell.config["driver"] == "sim" and cell.driver == "train"
+    assert [m["name"] for m in cell.end_to_end] == [
+        "setup_s", "train_transitions_per_s"]
+    cell = testing.tiny_cell("train-paper8-sac-0.12", b, tmp_path)
+    assert cell.traffic["rate_per_stream"] == 0.12
+    ctx, out = testing.tiny_run(cell, seconds=0.2)
+    assert testing.correct(out), out.checks
+    line = harness.result_line(cell, (ctx, out), False, ctx.devs)
+    assert set(line["metrics"]) == {"setup_s", "train_transitions_per_s"}
 
 
 def test_result_line_has_the_contract_keys():

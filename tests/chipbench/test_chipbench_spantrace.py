@@ -294,3 +294,93 @@ def test_traced_serve_run_reads_its_cold_loads():
     ms = harness.reader("model_load_ms").read(ctx, out)
     assert ms == pytest.approx(sum(e["dur"] for e in loads)
                                / len(loads) / 1e3)
+
+
+# ---- one reduction for every traced cell --------------------------------
+DEVICE_READERS = ("env_step_kernel_us", "env_step_roofline",
+                  "device_idle_share.sim", "device_idle_share.serve")
+
+
+class CellCtx(Ctx):
+    def __init__(self, device):
+        super().__init__(device)
+        self.config = json.loads((Path(harness.ROOT) / "chipbench/configs"
+                                  / "paper-8srv.json").read_text())
+        self.peaks = harness.peaks_for("TPU v5 lite")
+
+
+@pytest.mark.parametrize("source", ["span_excerpt", "scopes.xplane.pb"])
+def test_device_readers_read_the_same_under_both_reductions(source,
+                                                            tmp_path):
+    """The readers the benchmark had before the profile was reduced by
+    `spantrace` read the same under `devtrace` and `spantrace`, on a
+    recorded sim window and on a chip profile; the gaps keep their
+    lengths (their labels may change)."""
+    if source == "span_excerpt":
+        events, spans, scopes, launches = load_excerpt("span_excerpt.json")
+    else:
+        d = tmp_path / "plugins" / "profile" / "1"
+        d.mkdir(parents=True)
+        shutil.copy(DATA / source, d / "host.xplane.pb")
+        events, spans, scopes, launches = spantrace.load(str(tmp_path))
+    old = devtrace.reduce(events)
+    new = spantrace.reduce(events, spans, scopes, launches)
+    out = Outcome(e2e={}, attempted=0, failed=0, checks=[],
+                  counts={"B": 256, "T": 128})
+    read_any = 0
+    for m in DEVICE_READERS:
+        a = harness.reader(m).read(CellCtx(old), out)
+        b = harness.reader(m).read(CellCtx(new), out)
+        assert a == b, m
+        read_any += a is not None
+    assert read_any >= 2
+    assert sorted(g for _, g in old.idle_gaps) == sorted(
+        g for _, g in new.idle_gaps)
+    assert old.breakdown()["device_ops"] == new.breakdown()["device_ops"]
+
+
+def train_trace():
+    """A profiled round: collection (0-10 ms, device 2-8) with its push
+    (10-12), then the update schedule (12-40) of 4 updates, device busy
+    14-18, 22-26, 30-34 and 36-39."""
+    events = [mark("profiled", 0, 40), op("rollout", 2, 8),
+              op("update", 14, 18), op("update", 22, 26),
+              op("update", 30, 34), op("update", 36, 39)]
+    spans = [span("train_round", 0, 40, round=3),
+             span("window", 0, 10, window=3),
+             span("replay_push", 10, 12, n=4),
+             span("gradient_update", 12, 40, new_transitions=4)]
+    return spantrace.reduce(events, spans, {})
+
+
+def test_busy_and_idle_by_span_and_the_learner_readers():
+    d = train_trace()
+    assert d.busy_by_span == pytest.approx({"window": 0.006,
+                                            "gradient_update": 0.015})
+    assert sum(d.busy_by_span.values()) == pytest.approx(d.busy_s)
+    # gradient_update: 12-14, 18-22, 26-30, 34-36, 39-40 = 13 ms
+    assert d.idle_by_span["gradient_update"] == pytest.approx(0.013)
+    out = Outcome(e2e={}, attempted=0, failed=0, checks=[],
+                  counts={"profiled_updates": 4})
+    assert read("update_idle_ms", d, out) == pytest.approx(13.0 / 4)
+    assert read("update_device_us", d, out) == pytest.approx(15e3 / 4)
+    assert read("device_idle_share.train", d, out) == pytest.approx(
+        100.0 * 19 / 40)
+    for m in ("update_idle_ms", "update_device_us"):
+        assert read(m, d) is None                   # no updates counted
+        assert read(m, spantrace.reduce(*window_trace()), out) is None
+        assert read(m, None, out) is None
+
+
+def test_learner_span_readers():
+    spans = [{"name": "gradient_update", "dur": 30e3},
+             {"name": "gradient_update", "dur": 50e3},
+             {"name": "replay_push", "dur": 2e3},
+             {"name": "replay_push", "dur": 4e3}]
+    out = Outcome(e2e={}, attempted=0, failed=0, checks=[], spans=spans,
+                  counts={"updates": 40, "rounds": 2})
+    assert read("gradient_update_ms", None, out) == pytest.approx(2.0)
+    assert read("replay_push_ms", None, out) == pytest.approx(3.0)
+    out.counts = {}
+    assert read("gradient_update_ms", None, out) is None
+    assert read("replay_push_ms", None, out) is None
